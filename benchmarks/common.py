@@ -80,7 +80,12 @@ def run_solver_subprocess(
     args: list[str], n_devices: int, timeout=1800,
     module: str = "repro.launch.solve",
 ) -> str:
+    """Run a driver module on ``n_devices`` emulated host devices.
+
+    The child is pinned to the CPU: these runs are count gates, and a
+    parent that already holds a TPU would make a TPU child hang."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     cmd = [sys.executable, "-m", module, "--devices", str(n_devices)] + args
     r = subprocess.run(cmd, capture_output=True, text=True, timeout=timeout, env=env)

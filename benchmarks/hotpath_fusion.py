@@ -91,7 +91,6 @@ def _unfused_hs_stencil_solver(mesh, p, n_shards, *, tol, maxiter):
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.cg import SolveResult
@@ -127,12 +126,12 @@ def _unfused_hs_stencil_solver(mesh, p, n_shards, *, tol, maxiter):
         c = lax.while_loop(cond, body, (i0, x0, r, r, rr, rr))
         return c[1][None], c[0], c[5], bb
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         lambda b, x0: body_fn(b[0], x0[0]),
         mesh=mesh,
         in_specs=(P("shards", None), P("shards", None)),
         out_specs=(P("shards", None), P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit
@@ -159,6 +158,7 @@ def executed(side: int = 24, maxiter: int = 200) -> list[dict]:
         REPO + os.pathsep + SRC + os.pathsep + env.get("PYTHONPATH", "")
     )
     env["JAX_ENABLE_X64"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"  # a CPU count gate; the parent holds JAX
     code = (
         "import json, benchmarks.hotpath_fusion as h; "
         f"print('ROWS=' + json.dumps(h._executed_body({side}, {maxiter})))"

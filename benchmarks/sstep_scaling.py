@@ -249,6 +249,7 @@ def agreement(cases=AGREE_CASES, side: int = AGREE_SIDE):
     rows = []
     for n_shards, svals in cases:
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"  # a CPU count gate; the parent holds JAX
         env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
         env["XLA_FLAGS"] = (
             env.get("XLA_FLAGS", "")

@@ -899,6 +899,8 @@ def solve(
             entry["iters_cols"] = [
                 int(v) for v in np.asarray(res.iters_cols)
             ]
+        if tr.kernels:
+            entry["kernels"] = trace.kernels_by_backend(tr)
         if rec is not None:
             entry["telemetry"] = rec.ledger()
         payload["solvers"][label] = entry

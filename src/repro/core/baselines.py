@@ -115,8 +115,6 @@ def make_naive_solver(
     axis: str = "shards",
 ):
     """Jitted Ginkgo-analog CG solver: (b, x0) -> SolveResult."""
-    from jax.experimental.shard_map import shard_map
-
     pre = precond or identity_precond()
     mat_specs = dist_specs(mat)
 
@@ -132,12 +130,12 @@ def make_naive_solver(
         )
         return x[None], iters, rr, bb
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(mat_specs, pre.specs, P("shards", None), P("shards", None)),
         out_specs=(P("shards", None), P(), P(), P()),
-        check_rep=False,  # jax 0.4.37: no replication rule for while_loop
+        check_vma=False,  # loop carries are not annotated as shard-varying
     )
 
     @jax.jit
@@ -150,15 +148,13 @@ def make_naive_solver(
 
 def make_naive_spmv(mesh, mat: DistMat, axis: str = "shards"):
     """Jitted Ginkgo-analog distributed SpMV."""
-    from jax.experimental.shard_map import shard_map
-
     specs = dist_specs(mat)
 
     def fn(m, x):
         mb = local_block(m)
         return spmv_naive_shard(mb, x[0], axis)[None]
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(specs, P("shards", None)),

@@ -404,6 +404,37 @@ def _pipecg_body(
     return c[1], c[0], c[11], bb
 
 
+def _gram_solve(G, rhs):
+    """``G^{-1} rhs`` for a small (k, k) Gram matrix ``G``.
+
+    Gaussian elimination with partial pivoting and back substitution,
+    written in plain array ops (no matmul, so no reduced-precision MXU
+    pass either): it compiles for every dtype the device has, where
+    ``jnp.linalg.solve`` needs an LU routine that the TPU lacks in f64. A
+    singular ``G`` yields non-finite entries, as LU does, for the callers'
+    breakdown guards.
+    """
+    k = G.shape[0]
+    m = jnp.concatenate([G, rhs.reshape(k, -1)], axis=1)
+    rows = jnp.arange(k)
+
+    def eliminate(j, m):
+        col = jnp.abs(m[:, j])
+        p = jnp.argmax(jnp.where(rows >= j, col, -1.0))
+        m = m.at[j].set(m[p]).at[p].set(m[j])
+        f = jnp.where(rows > j, m[:, j] / m[j, j], 0.0)
+        return m - f[:, None] * m[j][None, :]
+
+    def substitute(i, x):
+        j = k - 1 - i
+        done = jnp.sum(m[j, :k, None] * x, axis=0)  # rows > j only
+        return x.at[j].set((m[j, k:] - done) / m[j, j])
+
+    m = lax.fori_loop(0, k, eliminate, m)
+    x = lax.fori_loop(0, k, substitute, jnp.zeros_like(m[:, k:]))
+    return x.reshape(rhs.shape)
+
+
 def _sstep_body(
     A, pre: Preconditioner, pdata, b, x0, *, tol, maxiter, s, axis, ops,
     mat=None, telemetry=False,
@@ -499,13 +530,13 @@ def _sstep_body(
         C = C * dinv[None, :]
         g = g * dinv
         # A-conjugate against previous block: B = Gqq^{-1} C (Gqq from prev).
-        B = jnp.linalg.solve(Gqq + 1e-300 * eye, C)
+        B = _gram_solve(Gqq + 1e-300 * eye, C)
         with trace.region("reductions"):
             # Q = Pb D - Qp B ; WQ = Wb D - Wp B — ONE fused pass
             Q, WQ = ops.sstep_basis(B, dinv, Qp, Pb, Wp, Wb)
         Gq = Gpp - B.T @ C - C.T @ B + B.T @ Gqq @ B
         # Q^T r == g because r ⟂ span(previous block) in exact arithmetic.
-        a = jnp.linalg.solve(Gq + 1e-300 * eye, g)
+        a = _gram_solve(Gq + 1e-300 * eye, g)
         # breakdown guard: a non-finite step means the basis lost numerical
         # independence despite the scaling (s too large for this spectrum).
         # Freeze x/r and stop — the caller sees a loud non-converged
@@ -529,14 +560,7 @@ def _sstep_body(
     ok0 = jnp.asarray(True)
     # mark the zero-init blocks as shard-varying for the while_loop carry
     ax_names = (axis,) if isinstance(axis, str) else tuple(axis)
-    _pvary = (
-        (lambda v: lax.pcast(v, ax_names, to="varying"))
-        if hasattr(lax, "pcast")
-        else (lambda v: lax.pvary(v, ax_names))
-        if hasattr(lax, "pvary")
-        else (lambda v: v)  # check_rep=False: no replication tracking needed
-    )
-    Q0 = _pvary(jnp.zeros((R, s), dt))
+    Q0 = lax.pcast(jnp.zeros((R, s), dt), ax_names, to="varying")
     c = lax.while_loop(cond, body, (i0, ok0, x0, r, Q0, Q0, eye, bb))
     return c[2], c[0], c[7], bb
 
@@ -577,7 +601,7 @@ def _block_hs_body(A, B, X0, *, tol, maxiter, axis, ops, telemetry=False):
         m2 = md[:, None] * md[None, :]
         Gm = G * m2 + jnp.diag(1.0 - md)
         ridge = jnp.finfo(dt).eps * jnp.trace(Gm) / nrhs
-        return jnp.linalg.solve(Gm + ridge * eye, RHS * m2)
+        return _gram_solve(Gm + ridge * eye, RHS * m2)
 
     def cond(c):
         i, X, R_, Pb, RR, it_cols = c
@@ -634,6 +658,31 @@ VARIANTS = tuple(_BODIES)
 # ---------------------------------------------------------------------------
 
 
+def _refuse_on_tpu(variant: str, mat: DistMat, ops) -> None:
+    """Refuse, on a TPU backend, the solver paths a v5e computed wrong.
+
+    Block-HS with the jnp reference block ops (every f64 run, or
+    ``kernels='jnp'``) returned NaN in f32 and did not converge in f64, and
+    f32 ``sstep`` did not converge with either kernel set. f64 ``sstep``
+    and f32 block-HS on the Pallas block kernels converged (PERF.md).
+    """
+    if jax.default_backend() != "tpu":
+        return
+    f64 = jnp.dtype(mat.data_ext.dtype).itemsize == 8
+    if variant == "block" and (f64 or ops.backend == "jnp"):
+        raise ValueError(
+            "block-HS (nrhs > 1) does not run on a TPU with the jnp block "
+            "ops (f64, or kernels='jnp'): on a v5e it returned NaN in f32 "
+            "and did not converge in f64. Solve one right-hand side at a "
+            "time (serve with --slots 1), or in f32 with the Pallas kernels."
+        )
+    if variant == "sstep" and not f64:
+        raise ValueError(
+            "sstep does not converge in f32 on a TPU (seen on a v5e with "
+            "both kernel sets): solve in f64 or use variant 'hs'."
+        )
+
+
 def make_solver(
     mesh,
     mat: DistMat,
@@ -686,14 +735,13 @@ def make_solver(
         ``spmv.shard_vector``) and the result carries the (S, R) solution,
         the executed iteration count, and ``||r||^2`` / ``||b||^2``.
     """
-    from jax.experimental.shard_map import shard_map
-
     pre = precond or identity_precond()
     body = _BODIES[variant]
     kw = dict(
         tol=tol, maxiter=maxiter, axis=axis, ops=kd.ops_for(kernels),
         telemetry=telemetry,
     )
+    _refuse_on_tpu(variant, mat, kw["ops"])
     if variant == "sstep":
         kw["s"] = s
     if variant == "pipecg":
@@ -716,12 +764,12 @@ def make_solver(
             x, iters, rr, bb = body(A, pre, pl, b[0], x0[0], **kwb)
         return x[None], iters, rr, bb
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(mat_specs, pre.specs, P(axis, None), P(axis, None)),
         out_specs=(P(axis, None), P(), P(), P()),
-        check_rep=False,  # jax 0.4.37: no replication rule for while_loop
+        check_vma=False,  # loop carries are not annotated as shard-varying
     )
 
     @jax.jit
@@ -753,11 +801,10 @@ def make_solver_fn(
     ``mat_like`` only supplies shapes/plan for the sharding specs; all other
     arguments as in :func:`make_solver`.
     """
-    from jax.experimental.shard_map import shard_map
-
     pre = precond or identity_precond()
     body = _BODIES[variant]
     kw = dict(tol=tol, maxiter=maxiter, axis=axis, ops=kd.ops_for(kernels))
+    _refuse_on_tpu(variant, mat_like, kw["ops"])
     if variant == "sstep":
         kw["s"] = s
     if variant == "pipecg":
@@ -774,12 +821,12 @@ def make_solver_fn(
             x, iters, rr, bb = body(A, pre, pl, b[0], x0[0], **kwb)
         return x[None], iters, rr, bb
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(mat_specs, pre.specs, P(axis, None), P(axis, None)),
         out_specs=(P(axis, None), P(), P(), P()),
-        check_rep=False,  # jax 0.4.37: no replication rule for while_loop
+        check_vma=False,  # loop carries are not annotated as shard-varying
     )
 
     @jax.jit
@@ -871,15 +918,17 @@ def make_block_solver(
 
     Only the identity preconditioner is supported (the block recurrences
     assume the unpreconditioned R'R Gram); pass ``precond=None``.
+    ``solve.func`` is the jitted ``solve(mat, B, X0)`` with the matrix as a
+    runtime argument, lowerable from ShapeDtypeStruct trees like
+    :func:`make_solver_fn`.
     """
-    from jax.experimental.shard_map import shard_map
-
     if precond is not None and not precond.is_identity:
         raise ValueError(
             "block-CG supports the identity preconditioner only; "
             "use make_solver(variant=...) per column for preconditioned solves"
         )
     ops = kd.ops_for(kernels)
+    _refuse_on_tpu("block", mat, ops)
     kw = dict(tol=tol, maxiter=maxiter, axis=axis, ops=ops,
               telemetry=telemetry)
     mat_specs = dist_specs(mat, axis)
@@ -887,11 +936,13 @@ def make_block_solver(
     def fn(m, Bv, X0):
         mb = local_block(m)
         A = lambda v: spmv_shard(mb, v, axis, overlap=overlap)
-        with overlap_default(overlap):
+        # full-precision matmuls for the Gram algebra: the TPU's default
+        # for f32 is one bf16 pass, too coarse for block CG to converge
+        with overlap_default(overlap), jax.default_matmul_precision("highest"):
             X, iters, it_cols, rr, bb = _block_hs_body(A, Bv[0], X0[0], **kw)
         return X[None], iters, it_cols, rr, bb
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(
@@ -900,17 +951,17 @@ def make_block_solver(
             P(axis, None, None),
         ),
         out_specs=(P(axis, None, None), P(), P(), P(), P()),
-        check_rep=False,  # jax 0.4.37: no replication rule for while_loop
+        check_vma=False,  # loop carries are not annotated as shard-varying
     )
 
     @jax.jit
-    def solve(Bv, X0):
-        X, iters, it_cols, rr, bb = mapped(mat, Bv, X0)
+    def solve(mat_arg, Bv, X0):
+        X, iters, it_cols, rr, bb = mapped(mat_arg, Bv, X0)
         return BlockSolveResult(
             x=X, iters=iters, iters_cols=it_cols, rr=rr, bb=bb
         )
 
-    return solve
+    return partial(solve, mat)
 
 
 def default_rhs_block(n: int, nrhs: int, dtype="float64"):

@@ -30,7 +30,6 @@ import dataclasses
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 
@@ -274,6 +273,16 @@ def _register(cls, data_fields, meta_fields):
         data_fields=data_fields,
         meta_fields=meta_fields,
     )(cls)
+
+
+def _host(a) -> np.ndarray:
+    """A global (S, ...) array as host numpy in the dtype JAX would give it.
+
+    Partition outputs stay on the host until ``spmv.shard_matrix`` places
+    each shard's block on its own device; staging the whole matrix through
+    ``jnp.asarray`` would first copy all of it onto the default device."""
+    a = np.asarray(a)
+    return a.astype(jax.dtypes.canonicalize_dtype(a.dtype), copy=False)
 
 
 def _size(a) -> int:
@@ -617,7 +626,7 @@ def _pack_interior_ell(shard_rows, R: int, dtype) -> ELLBlock:
     col = np.zeros((S, R, k), np.int32)
     for s, rows in enumerate(shard_rows):
         data[s], col[s] = _rows_to_ell(rows, R, k, dtype)
-    return ELLBlock(data=jnp.asarray(data), col=jnp.asarray(col))
+    return ELLBlock(data=_host(data), col=_host(col))
 
 
 def _pack_interior_hyb(shard_rows, R: int, dtype, k_typ: int | None = None) -> HYBBlock:
@@ -661,11 +670,11 @@ def _pack_interior_hyb(shard_rows, R: int, dtype, k_typ: int | None = None) -> H
         tail_col[s, : len(td)] = tc.astype(np.int32)
         tail_row[s, : len(td)] = trw.astype(np.int32)
     return HYBBlock(
-        data=jnp.asarray(data),
-        col=jnp.asarray(col),
-        tail_data=jnp.asarray(tail_data),
-        tail_col=jnp.asarray(tail_col),
-        tail_row=jnp.asarray(tail_row),
+        data=_host(data),
+        col=_host(col),
+        tail_data=_host(tail_data),
+        tail_col=_host(tail_col),
+        tail_row=_host(tail_row),
         n_tail=n_tail,
     )
 
@@ -703,8 +712,8 @@ def _pack_interior_bcsr(shard_rows, R: int, dtype, br: int, bc: int) -> BCSRBloc
         )
         bcol[s].reshape(n_brows, bpr)[:, :bpr_s] = bcl.reshape(nbr, bpr_s)
     return BCSRBlock(
-        blocks=jnp.asarray(blocks),
-        bcol=jnp.asarray(bcol),
+        blocks=_host(blocks),
+        bcol=_host(bcol),
         n_brows=n_brows,
         bpr=bpr,
         br=br,
@@ -1071,17 +1080,17 @@ def partition_csr(
 
     return DistMat(
         interior=interior,
-        data_ext=jnp.asarray(data_ext),
-        col_ext=jnp.asarray(col_ext),
-        bnd_rows=jnp.asarray(bnd_rows),
-        send_sel=jnp.asarray(send_sel),
+        data_ext=_host(data_ext),
+        col_ext=_host(col_ext),
+        bnd_rows=_host(bnd_rows),
+        send_sel=_host(send_sel),
         plan=plan,
         n_global=n,
         row_starts=part.row_starts,
         n_bnd=n_bnd,
-        ghost_data=jnp.asarray(ghost_data),
-        ghost_col=jnp.asarray(ghost_col),
-        ghost_pos=jnp.asarray(ghost_pos),
+        ghost_data=_host(ghost_data),
+        ghost_col=_host(ghost_col),
+        ghost_pos=_host(ghost_pos),
         halo_depth=eff_depth,
     )
 
@@ -1202,7 +1211,7 @@ def partition_stencil(
 
     B = max(max(n_bnd), 1)
     if fmt in ("ell", "auto"):
-        interior = ELLBlock(data=jnp.asarray(data_loc), col=jnp.asarray(col_loc))
+        interior = ELLBlock(data=_host(data_loc), col=_host(col_loc))
     else:
         interior = pack_interior(
             fmt, _ell_to_shard_rows(data_loc, col_loc), R, dtype=dtype,
@@ -1210,10 +1219,10 @@ def partition_stencil(
         )
     return DistMat(
         interior=interior,
-        data_ext=jnp.asarray(data_ext[:, :B]),
-        col_ext=jnp.asarray(col_ext[:, :B]),
-        bnd_rows=jnp.asarray(bnd_rows[:, :B]),
-        send_sel=jnp.asarray(send_sel),
+        data_ext=_host(data_ext[:, :B]),
+        col_ext=_host(col_ext[:, :B]),
+        bnd_rows=_host(bnd_rows[:, :B]),
+        send_sel=_host(send_sel),
         plan=plan,
         n_global=p.n,
         row_starts=part.row_starts,

@@ -25,6 +25,7 @@ import contextlib
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
@@ -483,8 +484,11 @@ def matrix_axis(mat: DistMat):
 
 def shard_vector(mesh, xp, axis="shards") -> jax.Array:
     """(S, R[, r]) padded host vector or RHS block -> device array sharded
-    over the shard axis (all trailing axes replicated)."""
-    xp = jnp.asarray(xp)
+    over the shard axis (all trailing axes replicated). Host input goes
+    straight to its shards, never through one device."""
+    if not isinstance(xp, jax.Array):
+        xp = np.asarray(xp)
+        xp = xp.astype(jax.dtypes.canonicalize_dtype(xp.dtype), copy=False)
     sh = jax.sharding.NamedSharding(
         mesh, P(axis, *([None] * (xp.ndim - 1)))
     )
@@ -509,8 +513,6 @@ def make_spmv(mesh, mat: DistMat, axis="shards", *, overlap: bool = True):
     :func:`spmv_shard`). ``axis`` is the mesh axis name — or the
     ``(rows, cols)`` tuple for 2-D grid meshes.
     """
-    from jax.experimental.shard_map import shard_map
-
     specs = dist_specs(mat, axis)
 
     def fn(m, x):
@@ -518,11 +520,11 @@ def make_spmv(mesh, mat: DistMat, axis="shards", *, overlap: bool = True):
         y = spmv_shard(mb, x[0], axis, overlap=overlap)
         return y[None]
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(specs, P(axis, None)),
         out_specs=P(axis, None),
-        check_rep=False,  # jax 0.4.37: no replication rule for pallas_call
+        check_vma=False,  # pallas_call outputs carry no varying annotation
     )
     return jax.jit(mapped)

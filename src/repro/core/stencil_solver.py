@@ -132,8 +132,6 @@ def make_stencil_solver_fn(
     vector ops (None = auto); ``overlap`` the communication-hiding schedule
     (see :func:`make_matvec` and ``core/cg.make_solver``).
     """
-    from jax.experimental.shard_map import shard_map
-
     pre = identity_precond()
     body = _BODIES[variant]
     kw = dict(tol=tol, maxiter=maxiter, axis=axis)
@@ -149,12 +147,12 @@ def make_stencil_solver_fn(
         x, iters, rr, bb = body(A, pre, (), b[0], x0[0], **kw)
         return x[None], iters, rr, bb
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P("shards", None), P("shards", None)),
         out_specs=(P("shards", None), P(), P(), P()),
-        check_rep=False,  # jax 0.4.37: no replication rule for while_loop
+        check_vma=False,  # loop carries are not annotated as shard-varying
     )
 
     @jax.jit

@@ -65,6 +65,9 @@ class PowerMonitor:
         cost: CostModel | None = None,
         devices_per_host: int = 4,  # the paper's nodes: 4 GPUs / dual-socket
     ):
+        from repro.roofline.hw import device_chip
+
+        device_chip()  # raises on a TPU the power model has no peaks for
         self.cost = cost or CostModel()
         self.model: PowerModel = self.cost.power
         self.n_devices = n_devices

@@ -72,6 +72,8 @@ class EnergyTrace:
     def __init__(self):
         self.sections: dict[str, dict[str, RegionTally]] = {}
         self.entries: dict[str, int] = {}
+        # dispatch op -> kernel backend it resolved to (kernels/dispatch.py)
+        self.kernels: dict[str, str] = {}
 
     def enter(self, section: str):
         self.entries[section] = self.entries.get(section, 0) + 1
@@ -225,6 +227,20 @@ def record_op(op: str, counts: OpCounts):
         if _scale != 1.0:
             counts = counts * _scale
         _trace.record(_section, current_region(), op, counts)
+
+
+def record_kernel(op: str, backend: str):
+    """Note the kernel backend a dispatch op resolved to (trace time)."""
+    if _trace is not None:
+        _trace.kernels[op] = backend
+
+
+def kernels_by_backend(tr: EnergyTrace) -> dict[str, list[str]]:
+    """``{backend: sorted op names}`` — the ledger's ``kernels`` block."""
+    out: dict[str, list[str]] = {}
+    for op, b in sorted(tr.kernels.items()):
+        out.setdefault(b, []).append(op)
+    return out
 
 
 def record_collective(n_scalars: int, itemsize: int = 8, op: str = "allreduce"):

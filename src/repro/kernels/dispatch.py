@@ -3,17 +3,21 @@
 The repo carries three implementations of every hot-path op:
 
 * ``pallas``    — the compiled Pallas TPU kernel (VMEM tiling, fused HBM
-  passes). Only meaningful on a TPU backend; f64 calls fall back to ``jnp``
-  (Mosaic has no f64).
+  passes). Only meaningful on a TPU backend, and f32 only: Mosaic has no
+  64-bit types, so an explicit ``pallas`` choice raises on f64 operands.
 * ``interpret`` — the same Pallas kernel run in interpret mode: exact kernel
-  semantics on CPU, used by tests to validate the TPU code path.
+  semantics on CPU, used by tests to validate the TPU code path. Refused on
+  a TPU backend, where nothing may run interpreted.
 * ``jnp``       — the pure-jnp reference (kernels/ref.py oracles). The
   default on CPU/GPU, where XLA fusion already does the right thing.
 
 Selection: explicit argument > ``set_backend``/``use_backend`` override >
-``REPRO_KERNELS`` env var > auto (TPU -> pallas, else jnp). Resolution
-happens at TRACE time — a jitted solver bakes in whichever backend was
-active when it was traced; build a fresh solver to switch.
+``REPRO_KERNELS`` env var > auto (TPU -> pallas, else jnp). Only auto
+resolution may send an f64 call on a TPU to ``jnp``; every op records the
+backend it resolved to in the active energy trace (the ledger's
+``kernels`` block). Resolution happens at TRACE time — a jitted solver
+bakes in whichever backend was active when it was traced; build a fresh
+solver to switch.
 
 Solvers obtain an :class:`OpSet` via :func:`ops_for` and call ops through
 it. Every op invocation is recorded in the active :class:`SweepLedger`
@@ -92,12 +96,8 @@ def available_backend() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "jnp"
 
 
-def resolve(choice: str | None = None) -> str:
-    """Resolve a backend name: explicit > override > env > auto.
-
-    ``None``/``''``/``'auto'`` at any level defers to the next one, so an
-    explicit ``kernels='auto'`` still honors ``use_backend``/``REPRO_KERNELS``.
-    """
+def _explicit(choice: str | None) -> str | None:
+    """The first explicit backend in precedence order, or None (auto)."""
     for cand in (choice, _override, os.environ.get(ENV_VAR)):
         if cand is None:
             continue
@@ -109,7 +109,16 @@ def resolve(choice: str | None = None) -> str:
                 f"unknown kernel backend {cand!r}; want one of {BACKENDS} or 'auto'"
             )
         return cand
-    return available_backend()
+    return None
+
+
+def resolve(choice: str | None = None) -> str:
+    """Resolve a backend name: explicit > override > env > auto.
+
+    ``None``/``''``/``'auto'`` at any level defers to the next one, so an
+    explicit ``kernels='auto'`` still honors ``use_backend``/``REPRO_KERNELS``.
+    """
+    return _explicit(choice) or available_backend()
 
 
 def backend() -> str:
@@ -220,13 +229,6 @@ def _record(name: str, counts: OpCounts | None = None):
 # ---------------------------------------------------------------------------
 
 
-def _pallas_mode(backend_name: str, dtype) -> str:
-    """Compiled-pallas f64 calls fall back to jnp (Mosaic has no f64)."""
-    if backend_name == "pallas" and jnp.dtype(dtype) == jnp.dtype("float64"):
-        return "jnp"
-    return backend_name
-
-
 # executed-counts formulas shared with the other instrumented layers
 _axpy_counts = trace.streamed_axpy_counts
 
@@ -234,13 +236,38 @@ _axpy_counts = trace.streamed_axpy_counts
 class OpSet:
     """Hot-path ops bound to one backend. Obtain via :func:`ops_for`."""
 
-    def __init__(self, backend_name: str, *, chunk: int = 65536):
+    def __init__(self, backend_name: str, *, chunk: int = 65536,
+                 auto: bool = False):
         assert backend_name in BACKENDS
+        if backend_name == "interpret" and jax.default_backend() == "tpu":
+            raise ValueError(
+                "kernels='interpret' runs the Pallas kernels on the host; "
+                "on a TPU backend use 'pallas' (or 'auto')"
+            )
         self.backend = backend_name
         self.chunk = chunk
+        self.auto = auto
 
     def __repr__(self):
         return f"OpSet(backend={self.backend!r})"
+
+    def _mode(self, op: str, dtype) -> str:
+        """The backend ``op`` runs on for operands of ``dtype``.
+
+        Mosaic has no 64-bit types: under auto resolution an f64 call runs
+        the jnp reference, an explicit ``pallas`` choice raises. The choice
+        is recorded in the active energy trace."""
+        b = self.backend
+        if b == "pallas" and jnp.dtype(dtype).itemsize == 8:
+            if not self.auto:
+                raise ValueError(
+                    f"kernels='pallas' cannot run {op} on {jnp.dtype(dtype)} "
+                    "operands: the TPU kernel compiler has no 64-bit types. "
+                    "Solve in f32 (x64=False) or use kernels='auto'/'jnp'."
+                )
+            b = "jnp"
+        trace.record_kernel(op, b)
+        return b
 
     # -- fused vector ops (1 HBM sweep each) --------------------------------
 
@@ -252,7 +279,7 @@ class OpSet:
         """
         _require_1d("axpy", x, y)
         _record("axpy", _axpy_counts(x.size, x.dtype.itemsize))
-        b = _pallas_mode(self.backend, x.dtype)
+        b = self._mode("axpy", x.dtype)
         if b == "jnp":
             return ref.fused_axpy_ref(a, x, y)
         return fused_axpy(a, x, y, chunk=self.chunk,
@@ -267,7 +294,7 @@ class OpSet:
         """
         _require_1d("fused_axpy2", x1, y1, x2, y2)
         _record("fused_axpy2", _axpy_counts(x1.size, x1.dtype.itemsize, 2))
-        b = _pallas_mode(self.backend, x1.dtype)
+        b = self._mode("fused_axpy2", x1.dtype)
         if b == "jnp":
             return ref.fused_axpy2_ref(a1, x1, y1, a2, x2, y2)
         return fused_axpy2(a1, x1, y1, a2, x2, y2, chunk=self.chunk,
@@ -289,7 +316,7 @@ class OpSet:
             "fused_axpy2_dots",
             _axpy_counts(n, ib, 2) + OpCounts(flops=2.0 * n),
         )
-        b = _pallas_mode(self.backend, x1.dtype)
+        b = self._mode("fused_axpy2_dots", x1.dtype)
         if b == "jnp":
             return ref.fused_axpy2_dots_ref(a1, x1, y1, a2, x2, y2)
         return fused_axpy2_dots(a1, x1, y1, a2, x2, y2, chunk=self.chunk,
@@ -305,7 +332,7 @@ class OpSet:
         """
         _require_1d("fused_dots_n", *[a for p in pairs for a in p])
         _record("fused_dots_n", trace.local_dots_counts(pairs))
-        b = _pallas_mode(self.backend, pairs[0][0].dtype)
+        b = self._mode("fused_dots_n", pairs[0][0].dtype)
         if b == "jnp":
             return ref.fused_dots_n_ref(pairs)
         return fused_dots_n(pairs, chunk=self.chunk,
@@ -322,7 +349,7 @@ class OpSet:
         Order-sensitive (XᵀY != YᵀX), unlike the scalar dots.
         """
         _record("block_gram", trace.block_gram_counts(pairs))
-        b = _pallas_mode(self.backend, pairs[0][0].dtype)
+        b = self._mode("block_gram", pairs[0][0].dtype)
         if b == "jnp":
             return ref.block_gram_ref(pairs)
         return block_gram(pairs, interpret=(b == "interpret"))
@@ -335,11 +362,12 @@ class OpSet:
         n, r = x.shape
         _record("block_update", trace.block_update_counts(
             n, r, x.dtype.itemsize))
-        b = _pallas_mode(self.backend, x.dtype)
+        b = self._mode("block_update", x.dtype)
         if b == "jnp":
             return ref.block_update_ref(m, x, y, mask)
-        return block_update(m, x, y, mask, chunk=self.chunk,
-                            interpret=(b == "interpret"))
+        # the (n, r) kernels keep their own row chunk: ``self.chunk`` sizes
+        # 1-D vector blocks, and (chunk, r) tiles are padded to 128 lanes
+        return block_update(m, x, y, mask, interpret=(b == "interpret"))
 
     def block_update2(self, a1, x1, y1, a2, x2, y2):
         """``(y1 + x1 @ a1, y2 + x2 @ a2)`` — the block-CG X/R update pair
@@ -347,10 +375,10 @@ class OpSet:
         n, r = x1.shape
         _record("block_update2", trace.block_update_counts(
             n, r, x1.dtype.itemsize, terms=2))
-        b = _pallas_mode(self.backend, x1.dtype)
+        b = self._mode("block_update2", x1.dtype)
         if b == "jnp":
             return ref.block_update2_ref(a1, x1, y1, a2, x2, y2)
-        return block_update2(a1, x1, y1, a2, x2, y2, chunk=self.chunk,
+        return block_update2(a1, x1, y1, a2, x2, y2,
                              interpret=(b == "interpret"))
 
     # -- s-step block ops (1 HBM sweep each) --------------------------------
@@ -374,7 +402,7 @@ class OpSet:
                 hbm_bytes=float((3 * s + 1) * n + 2 * s * s + s + 1) * ib,
             ),
         )
-        b = _pallas_mode(self.backend, pb.dtype)
+        b = self._mode("sstep_gram", pb.dtype)
         if b == "jnp":
             return ref.sstep_gram_ref(pb, wb, wp, r)
         return sstep_gram(pb, wb, wp, r, interpret=(b == "interpret"))
@@ -392,7 +420,7 @@ class OpSet:
                 hbm_bytes=6.0 * n * s * ib,
             ),
         )
-        bk = _pallas_mode(self.backend, pb.dtype)
+        bk = self._mode("sstep_basis", pb.dtype)
         if bk == "jnp":
             return ref.sstep_basis_ref(b, dinv, qp, pb, wp, wb)
         return sstep_basis(b, dinv, qp, pb, wp, wb,
@@ -410,7 +438,7 @@ class OpSet:
                 hbm_bytes=float(2 * n * s + 4 * n) * ib,
             ),
         )
-        b = _pallas_mode(self.backend, q.dtype)
+        b = self._mode("sstep_update", q.dtype)
         if b == "jnp":
             return ref.sstep_update_ref(a, q, wq, x, r)
         return sstep_update(a, q, wq, x, r, interpret=(b == "interpret"))
@@ -438,7 +466,7 @@ class OpSet:
                 hbm_bytes=float(n + prev_halo.size + next_halo.size + n) * ib,
             ),
         )
-        b = _pallas_mode(self.backend, x3.dtype)
+        b = self._mode("stencil_matvec", x3.dtype)
         if b == "jnp":
             return ref.stencil_halo_ref(
                 x3, prev_halo, next_halo, stencil=stencil, aniso=aniso
@@ -470,7 +498,7 @@ class OpSet:
                 hbm_matrix_bytes=mat_bytes,
             ),
         )
-        backend_name = _pallas_mode(self.backend, x.dtype)
+        backend_name = self._mode("bcsr_spmv", x.dtype)
         x, flat, n_out = bcsr_prepare_x(
             blocks, x, n_brows=n_brows, bpr=bpr, n_out=n_out
         )
@@ -501,7 +529,7 @@ class OpSet:
                 hbm_matrix_bytes=mat_bytes,
             ),
         )
-        backend_name = _pallas_mode(self.backend, x.dtype)
+        backend_name = self._mode("bcsr_spmm", x.dtype)
         x, flat, n_out = bcsr_prepare_xb(
             blocks, x, n_brows=n_brows, bpr=bpr, n_out=n_out
         )
@@ -532,7 +560,7 @@ class OpSet:
             "stencil_boundary",
             OpCounts(flops=2.0 * k * 2 * n_pl, hbm_bytes=8.0 * n_pl * ib),
         )
-        b = _pallas_mode(self.backend, x3.dtype)
+        b = self._mode("stencil_boundary", x3.dtype)
         if b == "jnp":
             return ref.stencil_boundary_ref(
                 x3, prev_halo, next_halo, stencil=stencil, aniso=aniso
@@ -549,4 +577,5 @@ def ops_for(kernels: str | None = None, *, chunk: int = 65536) -> OpSet:
     ``kernels``: None/'auto' (resolve from override/env/backend) or one of
     ``BACKENDS``. Solver factories thread their ``kernels=`` argument here.
     """
-    return OpSet(resolve(kernels), chunk=chunk)
+    name = _explicit(kernels)
+    return OpSet(name or available_backend(), chunk=chunk, auto=name is None)

@@ -39,6 +39,14 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.x32 import pallas_call
+
+
+# The (n, r) block kernels contract on the MXU, whose default for f32
+# operands is a single bf16 pass (about 3 significant digits): too coarse
+# for CG's Gram algebra, which then stalls or diverges. Ask for full f32.
+_F32 = lax.Precision.HIGHEST
+
 
 def _round_up(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
@@ -70,6 +78,14 @@ def _valid_mask(i, chunk: int, n: int):
     """
     lane = lax.broadcasted_iota(jnp.int32, (1, chunk), 1).reshape(chunk)
     return (i * chunk + lane) < n
+
+
+def _valid_rows(i, chunk: int, n: int, r: int):
+    """(chunk, r) bool mask of in-range rows of an (n, r) block at grid
+    step ``i``. Built 2-D: Mosaic cannot reshape a (chunk,) mask to
+    (chunk, 1)."""
+    row = lax.broadcasted_iota(jnp.int32, (chunk, r), 0)
+    return (i * chunk + row) < n
 
 
 # ---------------------------------------------------------------------------
@@ -131,7 +147,7 @@ def fused_dots_n(pairs, *, chunk: int = 65536, interpret: bool = False) -> jax.A
         for j, (a, b) in enumerate(prods):
             out_ref[j] += jnp.sum(jnp.where(valid, vals[a] * vals[b], zero))
 
-    partials = pl.pallas_call(
+    partials = pallas_call(
         kernel,
         grid=(grid,),
         in_specs=[spec] * len(uniq),
@@ -160,7 +176,7 @@ def fused_axpy(a, x, y, *, chunk: int = 65536, interpret: bool = False):
     chunk_eff, grid = _chunking(n, chunk)
     spec = pl.BlockSpec((chunk_eff,), lambda i: (i,))
     av = jnp.asarray(a, x.dtype).reshape(1)
-    return pl.pallas_call(
+    return pallas_call(
         _axpy_kernel,
         grid=(grid,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), spec, spec],
@@ -183,7 +199,7 @@ def fused_axpy2(a1, x1, y1, a2, x2, y2, *, chunk: int = 65536,
     chunk_eff, grid = _chunking(n, chunk)
     spec = pl.BlockSpec((chunk_eff,), lambda i: (i,))
     av = jnp.stack([jnp.asarray(a1, x1.dtype), jnp.asarray(a2, x1.dtype)])
-    return pl.pallas_call(
+    return pallas_call(
         _axpy2_kernel,
         grid=(grid,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + [spec] * 4,
@@ -220,7 +236,7 @@ def fused_axpy2_dots(a1, x1, y1, a2, x2, y2, *, chunk: int = 65536,
         valid = _valid_mask(i, chunk_eff, n)
         d_ref[0] += jnp.sum(jnp.where(valid, v2 * v2, jnp.zeros((), v2.dtype)))
 
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(grid,),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + [spec] * 4,
@@ -308,15 +324,15 @@ def block_gram(pairs, *, chunk: int = 1024, interpret: bool = False):
             def _init(out_ref=out_ref):
                 out_ref[...] = jnp.zeros_like(out_ref)
 
-        valid = _valid_mask(i, chunk_eff, n)
+        valid = _valid_rows(i, chunk_eff, n, r)
         zero = jnp.zeros((), dt)
-        vals = [jnp.where(valid[:, None], t[...], zero) for t in ins]
+        vals = [jnp.where(valid, t[...], zero) for t in ins]
         for j, (a, b) in enumerate(prods):
             outs[j][...] += jnp.dot(
-                vals[a].T, vals[b], preferred_element_type=dt
+                vals[a].T, vals[b], preferred_element_type=dt, precision=_F32
             )
 
-    grams = pl.pallas_call(
+    grams = pallas_call(
         kernel,
         grid=(grid,),
         in_specs=[spec] * len(uniq),
@@ -341,10 +357,11 @@ def block_update(m, x, y, mask=None, *, chunk: int = 1024,
 
     def kernel(m_ref, k_ref, x_ref, y_ref, o_ref):
         o_ref[...] = y_ref[...] * k_ref[...] + jnp.dot(
-            x_ref[...], m_ref[...], preferred_element_type=o_ref.dtype
+            x_ref[...], m_ref[...], preferred_element_type=o_ref.dtype,
+            precision=_F32
         )
 
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(grid,),
         in_specs=[
@@ -374,13 +391,15 @@ def block_update2(a1, x1, y1, a2, x2, y2, *, chunk: int = 1024,
 
     def kernel(a_ref, x1_ref, y1_ref, x2_ref, y2_ref, o1_ref, o2_ref):
         o1_ref[...] = y1_ref[...] + jnp.dot(
-            x1_ref[...], a_ref[0], preferred_element_type=o1_ref.dtype
+            x1_ref[...], a_ref[0], preferred_element_type=o1_ref.dtype,
+            precision=_F32
         )
         o2_ref[...] = y2_ref[...] + jnp.dot(
-            x2_ref[...], a_ref[1], preferred_element_type=o2_ref.dtype
+            x2_ref[...], a_ref[1], preferred_element_type=o2_ref.dtype,
+            precision=_F32
         )
 
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(grid,),
         in_specs=[pl.BlockSpec((2, r, r), lambda i: (0, 0, 0))] + [spec] * 4,
@@ -438,20 +457,24 @@ def sstep_gram(pb, wb, wp, r, *, chunk: int = 1024, interpret: bool = False):
             for j in range(s + 1):
                 v_ref[j] = jnp.zeros((), v_ref.dtype)
 
-        valid = _valid_mask(i, chunk_eff, n)
+        valid = _valid_rows(i, chunk_eff, n, s)
         zero = jnp.zeros((), dt)
-        p = jnp.where(valid[:, None], p_ref[...], zero)
-        w = jnp.where(valid[:, None], w_ref[...], zero)
-        wpv = jnp.where(valid[:, None], wp_ref[...], zero)
-        rv = jnp.where(valid, r_ref[...], zero)
-        gpp_ref[...] += jnp.dot(p.T, w, preferred_element_type=dt)
-        c_ref[...] += jnp.dot(wpv.T, p, preferred_element_type=dt)
+        p = jnp.where(valid, p_ref[...], zero)
+        w = jnp.where(valid, w_ref[...], zero)
+        wpv = jnp.where(valid, wp_ref[...], zero)
+        rv = jnp.where(_valid_mask(i, chunk_eff, n), r_ref[...], zero)
+        gpp_ref[...] += jnp.dot(
+            p.T, w, preferred_element_type=dt, precision=_F32
+        )
+        c_ref[...] += jnp.dot(
+            wpv.T, p, preferred_element_type=dt, precision=_F32
+        )
         g = jnp.sum(p * rv[:, None], axis=0)
         for j in range(s):
             v_ref[j] += g[j]
         v_ref[s] += jnp.sum(rv * rv)
 
-    gpp, c, v = pl.pallas_call(
+    gpp, c, v = pallas_call(
         kernel,
         grid=(grid,),
         in_specs=[spec, spec, spec, vspec],
@@ -480,13 +503,15 @@ def sstep_basis(b, dinv, qp, pb, wp, wb, *, chunk: int = 1024,
 
     def kernel(b_ref, k_ref, qp_ref, pb_ref, wp_ref, wb_ref, o1_ref, o2_ref):
         o1_ref[...] = pb_ref[...] * k_ref[...] - jnp.dot(
-            qp_ref[...], b_ref[...], preferred_element_type=o1_ref.dtype
+            qp_ref[...], b_ref[...], preferred_element_type=o1_ref.dtype,
+            precision=_F32
         )
         o2_ref[...] = wb_ref[...] * k_ref[...] - jnp.dot(
-            wp_ref[...], b_ref[...], preferred_element_type=o2_ref.dtype
+            wp_ref[...], b_ref[...], preferred_element_type=o2_ref.dtype,
+            precision=_F32
         )
 
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(grid,),
         in_specs=[
@@ -516,13 +541,15 @@ def sstep_update(a, q, wq, x, r, *, chunk: int = 1024,
 
     def kernel(a_ref, q_ref, wq_ref, x_ref, r_ref, ox_ref, or_ref):
         ox_ref[...] = x_ref[...] + jnp.dot(
-            q_ref[...], a_ref[...], preferred_element_type=ox_ref.dtype
+            q_ref[...], a_ref[...], preferred_element_type=ox_ref.dtype,
+            precision=_F32
         )
         or_ref[...] = r_ref[...] - jnp.dot(
-            wq_ref[...], a_ref[...], preferred_element_type=or_ref.dtype
+            wq_ref[...], a_ref[...], preferred_element_type=or_ref.dtype,
+            precision=_F32
         )
 
-    ox, orr = pl.pallas_call(
+    ox, orr = pallas_call(
         kernel,
         grid=(grid,),
         in_specs=[

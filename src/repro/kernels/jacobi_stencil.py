@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.spmv_stencil import _shift_yx
+from repro.kernels.x32 import pallas_call
 
 
 def _jacobi_kernel(
@@ -72,7 +73,7 @@ def jacobi_stencil_sweep(
     )
     plane = lambda f: pl.BlockSpec((1, ny, nx), f)
     blk = pl.BlockSpec((bz, ny, nx), lambda i: (i, 0, 0))
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(nzb,),
         in_specs=[

@@ -1,8 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-``interpret`` defaults to True on CPU backends (this container) and False on
-TPU — kernels are *written for* TPU (explicit BlockSpec VMEM tiling) and
-*validated* in interpret mode against the pure-jnp oracles in ref.py.
+``interpret`` defaults to True on CPU backends and False on TPU, where
+``interpret=True`` is refused — kernels are *written for* TPU (explicit
+BlockSpec VMEM tiling) and *validated* in interpret mode against the
+pure-jnp oracles in ref.py.
 """
 
 from __future__ import annotations
@@ -26,12 +27,16 @@ from repro.kernels.spmv_stencil import (
 from repro.kernels.spmv_stencil import stencil_spmv_halo as _stencil_spmv_halo
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _interpret(flag: bool | None) -> bool:
+    """Interpret mode: the default off the TPU, refused on it."""
+    on_tpu = jax.default_backend() == "tpu"
+    if flag and on_tpu:
+        raise ValueError("interpret mode does not run on a TPU backend")
+    return (not on_tpu) if flag is None else flag
 
 
 def stencil_spmv(x, *, stencil="7pt", aniso=(1.0, 1.0, 1.0), bz=8, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     return _stencil_spmv(x, stencil=stencil, aniso=aniso, bz=bz, interpret=interpret)
 
 
@@ -46,7 +51,7 @@ def bcsr_spmv(blocks, bcol, x, *, n_brows, bpr, n_out=None, interpret=None):
     """
     from repro.kernels.spmv_bcsr import bcsr_finish_y, bcsr_prepare_x
 
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     x, flat, n_out = bcsr_prepare_x(
         blocks, x, n_brows=n_brows, bpr=bpr, n_out=n_out
     )
@@ -60,7 +65,7 @@ def stencil_spmv_halo(
     x, prev_halo, next_halo, *, stencil="7pt", aniso=(1.0, 1.0, 1.0), bz=8,
     interpret=None,
 ):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     return _stencil_spmv_halo(
         x, prev_halo, next_halo, stencil=stencil, aniso=aniso, bz=bz,
         interpret=interpret,
@@ -71,7 +76,7 @@ def stencil_spmv_boundary(
     x, prev_halo, next_halo, *, stencil="7pt", aniso=(1.0, 1.0, 1.0),
     interpret=None,
 ):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     return _stencil_spmv_boundary(
         x, prev_halo, next_halo, stencil=stencil, aniso=aniso,
         interpret=interpret,
@@ -79,29 +84,29 @@ def stencil_spmv_boundary(
 
 
 def fused_dots3(p, w, r, *, chunk=65536, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     return _fused_dots3(p, w, r, chunk=chunk, interpret=interpret)
 
 
 def fused_dots_n(pairs, *, chunk=65536, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     return _fused_dots_n(pairs, chunk=chunk, interpret=interpret)
 
 
 def fused_axpy(a, x, y, *, chunk=65536, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     return _fused_axpy(a, x, y, chunk=chunk, interpret=interpret)
 
 
 def fused_axpy2(a1, x1, y1, a2, x2, y2, *, chunk=65536, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     return _fused_axpy2(
         a1, x1, y1, a2, x2, y2, chunk=chunk, interpret=interpret
     )
 
 
 def fused_axpy2_dots(a1, x1, y1, a2, x2, y2, *, chunk=65536, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     return _fused_axpy2_dots(
         a1, x1, y1, a2, x2, y2, chunk=chunk, interpret=interpret
     )
@@ -111,7 +116,7 @@ def jacobi_stencil_sweep(
     x, b, dinv, *, stencil="7pt", aniso=(1.0, 1.0, 1.0), omega=1.0, bz=8,
     interpret=None,
 ):
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     return _jacobi(
         x, b, dinv, stencil=stencil, aniso=aniso, omega=omega, bz=bz,
         interpret=interpret,

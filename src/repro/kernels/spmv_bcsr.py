@@ -10,7 +10,9 @@ of the GPU kernel's latency hiding via massive thread parallelism.
 Layout: every block-row is padded to a uniform ``bpr`` blocks (padding blocks
 are all-zero with bcol=0, contributing nothing). Grid = (n_brows, bpr),
 j-fastest; the output tile for block-row i is revisited across j and
-accumulated in place (sequential TPU grid semantics).
+accumulated in place (sequential TPU grid semantics). The prefetched ids
+live in SMEM (1 MiB per core), so a matrix of more than ``_IDS_PER_CALL``
+blocks runs as one call per range of block rows.
 """
 
 from __future__ import annotations
@@ -19,11 +21,55 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.x32 import pallas_call
 
-def _bcsr_kernel(bcol_ref, blocks_ref, x_ref, y_ref, *, bpr):
+
+# Block ids one pallas_call may scalar-prefetch: half of a TPU core's 1 MiB
+# SMEM. Larger matrices run as several calls over ranges of block rows.
+_IDS_PER_CALL = 1 << 17
+
+
+def _bcsr_calls(kernel, blocks, bcol, x, *, n_brows, bpr, x_tile, y_tile,
+                interpret):
+    """Run ``kernel`` over the (block row, slot) grid, one pallas_call per
+    range of block rows whose block ids fit in SMEM; returns the
+    (n_brows, *y_tile) result. Each call reads the full ``blocks`` and
+    ``x`` arrays in place (its block-row offset lives in the index map)."""
+    _, br, bc = blocks.shape
+    x0, y0 = (0,) * len(x_tile), (0,) * len(y_tile)
+    step = max(_IDS_PER_CALL // bpr, 1)
+    outs = []
+    for r0 in range(0, n_brows, step):
+        r1 = min(r0 + step, n_brows)
+        off = r0 * bpr
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(r1 - r0, bpr),
+            in_specs=[
+                pl.BlockSpec(
+                    (1, br, bc),
+                    lambda i, j, ids, off=off: (off + i * bpr + j, 0, 0),
+                ),
+                pl.BlockSpec(
+                    (1, *x_tile), lambda i, j, ids: (ids[i * bpr + j], *x0)
+                ),
+            ],
+            out_specs=pl.BlockSpec((1, *y_tile), lambda i, j, ids: (i, *y0)),
+        )
+        outs.append(pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((r1 - r0, *y_tile), x.dtype),
+            interpret=interpret,
+        )(bcol[off:r1 * bpr], blocks, x))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+
+
+def _bcsr_kernel(bcol_ref, blocks_ref, x_ref, y_ref):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -31,8 +77,11 @@ def _bcsr_kernel(bcol_ref, blocks_ref, x_ref, y_ref, *, bpr):
         y_ref[...] = jnp.zeros_like(y_ref)
 
     blk = blocks_ref[0]  # (br, bc)
-    xv = x_ref[0]  # (bc,)
-    y_ref[0, :] += jnp.dot(blk, xv, preferred_element_type=y_ref.dtype)
+    xv = x_ref[0]  # (1, bc)
+    # (1, bc) . (br, bc)^T -> (1, br): the row-vector form of blk @ x
+    y_ref[0] += lax.dot_general(
+        xv, blk, (((1,), (1,)), ((), ())), preferred_element_type=y_ref.dtype
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("n_brows", "bpr", "interpret"))
@@ -46,25 +95,18 @@ def bcsr_spmv(
     interpret: bool = False,
 ) -> jax.Array:
     _, br, bc = blocks.shape
-    kernel = functools.partial(_bcsr_kernel, bpr=bpr)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_brows, bpr),
-        in_specs=[
-            pl.BlockSpec((1, br, bc), lambda i, j, bcol_ref: (i * bpr + j, 0, 0)),
-            pl.BlockSpec((1, bc), lambda i, j, bcol_ref: (bcol_ref[i * bpr + j], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, br), lambda i, j, bcol_ref: (i, 0)),
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_brows, br), x.dtype),
+    # x/y tiles travel as (1, 1, bc)/(1, 1, br) blocks of 3-D views: a
+    # (1, bc) block of the 2-D array would break the TPU's (8, 128) block
+    # rule, while a block whose last two dims span the array's is legal.
+    y = _bcsr_calls(
+        _bcsr_kernel, blocks, bcol, x.reshape(x.shape[0], 1, bc),
+        n_brows=n_brows, bpr=bpr, x_tile=(1, bc), y_tile=(1, br),
         interpret=interpret,
-    )(bcol, blocks, x)
+    )
+    return y.reshape(n_brows, br)
 
 
-def _bcsr_spmm_kernel(bcol_ref, blocks_ref, x_ref, y_ref, *, bpr):
+def _bcsr_spmm_kernel(bcol_ref, blocks_ref, x_ref, y_ref):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -92,26 +134,10 @@ def bcsr_spmm(
     stays identical to the SpMV kernel."""
     _, br, bc = blocks.shape
     r = x.shape[2]
-    kernel = functools.partial(_bcsr_spmm_kernel, bpr=bpr)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_brows, bpr),
-        in_specs=[
-            pl.BlockSpec(
-                (1, br, bc), lambda i, j, bcol_ref: (i * bpr + j, 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, bc, r), lambda i, j, bcol_ref: (bcol_ref[i * bpr + j], 0, 0)
-            ),
-        ],
-        out_specs=pl.BlockSpec((1, br, r), lambda i, j, bcol_ref: (i, 0, 0)),
+    return _bcsr_calls(
+        _bcsr_spmm_kernel, blocks, bcol, x, n_brows=n_brows, bpr=bpr,
+        x_tile=(bc, r), y_tile=(br, r), interpret=interpret,
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_brows, br, r), x.dtype),
-        interpret=interpret,
-    )(bcol, blocks, x)
 
 
 def bcsr_prepare_x(blocks, x, *, n_brows: int, bpr: int, n_out: int | None):

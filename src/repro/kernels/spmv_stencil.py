@@ -33,8 +33,10 @@ recomputes ONLY the slab's first and last output planes from the received
 halo planes. The overlapped distributed SpMV (core/stencil_solver.py) runs
 the full slab with zero halos while the ppermute is in flight — every
 interior plane is already final — and patches the two edge planes with this
-kernel on arrival. Both kernels share ``_stencil_core``, so the patched
-planes are bitwise identical to the serialized single-call result.
+kernel on arrival. Both kernels share ``_stencil_core``, which computes
+every output plane by the same expression on explicit neighbour planes, so
+the patched planes are bitwise identical to the serialized single-call
+result.
 """
 
 from __future__ import annotations
@@ -44,6 +46,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.x32 import pallas_call
 
 
 def _shift_yx(x: jax.Array, dy: int, dx: int) -> jax.Array:
@@ -61,25 +65,37 @@ def _shift_yx(x: jax.Array, dy: int, dx: int) -> jax.Array:
     return out
 
 
-def _stencil_core(c, prev_plane, next_plane, *, stencil, aniso):
-    """Shared 7pt/27pt arithmetic on a (bz, ny, nx) block + boundary planes."""
+def _stencil_core(y_ref, c, prev_plane, next_plane, *, stencil, aniso):
+    """Shared 7pt/27pt arithmetic: y_ref <- A (block c + boundary planes).
+
+    Output plane k reads only planes k-1, k, k+1. Every plane runs the same
+    (1, ny, nx) expression on explicit neighbour planes and is stored on its
+    own, so a one-plane call (the boundary kernel) is bitwise a plane of the
+    slab kernel. The diagonal product comes last, so a compiler that fuses
+    it with the subtraction (an FMA) does so in every plane alike.
+    """
+    planes = [prev_plane] + [c[k : k + 1] for k in range(c.shape[0])]
+    planes.append(next_plane)
     if stencil == "7pt":
         ax, ay, az = aniso
-        zm = jnp.concatenate([prev_plane, c[:-1]], axis=0)
-        zp = jnp.concatenate([c[1:], next_plane], axis=0)
-        y = (2.0 * (ax + ay + az)) * c
-        y = y - ax * (_shift_yx(c, 0, 1) + _shift_yx(c, 0, -1))
-        y = y - ay * (_shift_yx(c, 1, 0) + _shift_yx(c, -1, 0))
-        y = y - az * (zm + zp)
+        out = []
+        for below, cur, above in zip(planes, planes[1:], planes[2:]):
+            nb = ax * (_shift_yx(cur, 0, 1) + _shift_yx(cur, 0, -1))
+            nb = nb + ay * (_shift_yx(cur, 1, 0) + _shift_yx(cur, -1, 0))
+            nb = nb + az * (below + above)
+            out.append((2.0 * (ax + ay + az)) * cur - nb)
     else:  # 27pt
-        ext = jnp.concatenate([prev_plane, c, next_plane], axis=0)  # (bz+2,..)
-        s9 = jnp.zeros_like(ext)
-        for dy in (-1, 0, 1):
-            for dx in (-1, 0, 1):
-                s9 = s9 + _shift_yx(ext, dy, dx)
-        s27 = s9[:-2] + s9[1:-1] + s9[2:]
-        y = 27.0 * c - s27
-    return y
+        s9 = []
+        for pl_ in planes:
+            acc = jnp.zeros_like(pl_)
+            for dy in (-1, 0, 1):
+                for dx in (-1, 0, 1):
+                    acc = acc + _shift_yx(pl_, dy, dx)
+            s9.append(acc)
+        out = [27.0 * cur - (s9[k] + s9[k + 1] + s9[k + 2])
+               for k, cur in enumerate(planes[1:-1])]
+    for k, y in enumerate(out):
+        y_ref[k : k + 1] = y
 
 
 def _stencil_kernel(prev_ref, cur_ref, next_ref, y_ref, *, stencil, aniso, nzb):
@@ -91,8 +107,8 @@ def _stencil_kernel(prev_ref, cur_ref, next_ref, y_ref, *, stencil, aniso, nzb):
     nmask = jnp.where(i < nzb - 1, 1, 0).astype(dt)
     prev_plane = prev_ref[...] * pmask  # (1, ny, nx)
     next_plane = next_ref[...] * nmask
-    y_ref[...] = _stencil_core(
-        c, prev_plane, next_plane, stencil=stencil, aniso=aniso
+    _stencil_core(
+        y_ref, c, prev_plane, next_plane, stencil=stencil, aniso=aniso
     )
 
 
@@ -105,8 +121,8 @@ def _stencil_boundary_kernel(
     c = cur_ref[...]  # (1, ny, nx): plane 0 or nz-1
     prev_plane = jnp.where(i == 0, hp_ref[...], below_ref[...])
     next_plane = jnp.where(i == 0, above_ref[...], hn_ref[...])
-    y_ref[...] = _stencil_core(
-        c, prev_plane, next_plane, stencil=stencil, aniso=aniso
+    _stencil_core(
+        y_ref, c, prev_plane, next_plane, stencil=stencil, aniso=aniso
     )
 
 
@@ -119,8 +135,8 @@ def _stencil_halo_kernel(
     # planes at the slab edges (zeros arrive there for global-edge shards).
     prev_plane = jnp.where(i == 0, hp_ref[...], prev_ref[...])
     next_plane = jnp.where(i == nzb - 1, hn_ref[...], next_ref[...])
-    y_ref[...] = _stencil_core(
-        c, prev_plane, next_plane, stencil=stencil, aniso=aniso
+    _stencil_core(
+        y_ref, c, prev_plane, next_plane, stencil=stencil, aniso=aniso
     )
 
 
@@ -152,7 +168,7 @@ def stencil_spmv(
         (1, ny, nx), lambda i: (jnp.minimum(i * bz + bz, nz - 1), 0, 0)
     )
     cur_spec = pl.BlockSpec((bz, ny, nx), lambda i: (i, 0, 0))
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(nzb,),
         in_specs=[prev_spec, cur_spec, next_spec],
@@ -204,7 +220,7 @@ def stencil_spmv_halo(
         (1, ny, nx), lambda i: (jnp.minimum(i * bz + bz, nz - 1), 0, 0)
     )
     cur_spec = pl.BlockSpec((bz, ny, nx), lambda i: (i, 0, 0))
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(nzb,),
         in_specs=[plane, prev_spec, cur_spec, next_spec, plane],
@@ -250,7 +266,7 @@ def stencil_spmv_boundary(
     above = pl.BlockSpec(
         (1, ny, nx), lambda i: (jnp.minimum(i * (nz - 1) + 1, nz - 1), 0, 0)
     )
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(2,),
         in_specs=[plane, below, cur, above, plane],

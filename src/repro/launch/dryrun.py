@@ -146,10 +146,7 @@ def _cell_fns(cfg: ArchConfig, shape: ShapeConfig, mesh, microbatches: int = 1):
 
 
 def _cost_dict(compiled) -> dict:
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax<=0.4.x: list of per-program dicts
-        cost = cost[0] if cost else {}
-    return cost
+    return compiled.cost_analysis() or {}
 
 
 def _analyze(compiled, chips: int, model_flops: float) -> dict:
@@ -355,7 +352,6 @@ def run_solver_cell(
         else:
             # naive solver closes over the matrix; rebuild as arg-style
             from repro.core.cg import identity_precond
-            from jax.experimental.shard_map import shard_map
             from repro.core.baselines import _cg_unfused_body
             from repro.core.spmv import local_block
 
@@ -370,7 +366,7 @@ def run_solver_cell(
                 )
                 return x[None], iters, rr, bb
 
-            mapped = shard_map(
+            mapped = jax.shard_map(
                 fn,
                 mesh=mesh,
                 in_specs=(specs, jax.sharding.PartitionSpec("shards", None),

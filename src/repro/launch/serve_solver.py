@@ -50,6 +50,7 @@ import os
 import time
 from typing import Any, Callable
 
+from repro.launch import runtime  # stdlib-only: safe before jax
 from repro.obs.log import get_logger  # stdlib-only: safe before jax
 
 LOG = get_logger("serve")
@@ -596,16 +597,14 @@ def parse_args(argv=None):
 
 def main(argv=None):
     args = parse_args(argv)
-    if args.devices:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={args.devices}"
-        )
+    runtime.force_host_devices(args.devices)
     from repro.obs import log as olog
 
     olog.setup(args.log_level)
     import jax
 
+    runtime.check_devices(args.devices)
+    runtime.enable_compile_cache()
     jax.config.update("jax_enable_x64", True)
 
     from repro.api import ProblemSpec, parse_grid, write_ledger_json

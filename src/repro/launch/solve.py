@@ -39,7 +39,8 @@ reuse one compiled solver instead of re-partitioning and re-tracing.
 from __future__ import annotations
 
 import argparse
-import os
+
+from repro.launch import runtime
 
 
 def parse_args(argv=None):
@@ -125,11 +126,7 @@ def parse_args(argv=None):
 
 def main(argv=None):
     args = parse_args(argv)
-    if args.devices:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={args.devices}"
-        )
+    runtime.force_host_devices(args.devices)
     from repro.obs import log as olog
 
     olog.setup(args.log_level)
@@ -139,6 +136,8 @@ def main(argv=None):
     try:
         spec = api.ProblemSpec.from_args(args)
         config = api.SolverConfig.from_args(args)
+        runtime.check_devices(args.devices)
+        runtime.enable_compile_cache()
         api.solve(spec, config, ledger=args.ledger, profile=args.profile)
     except api.ConfigError as e:
         # the historical argparse-era behavior: message on stderr, exit 1

@@ -1,8 +1,8 @@
 """Hardware constants for the roofline/energy models.
 
-Target platform: Google TPU v5e (the dry-run target). The container itself is
-CPU-only; these constants parameterize the analytical models only and are never
-used to configure XLA.
+Target platform: Google TPU v5e. These constants parameterize the analytical
+models only and are never used to configure XLA; :func:`device_chip` refuses
+a TPU they do not describe.
 """
 
 from __future__ import annotations
@@ -94,3 +94,27 @@ HOST_XEON = HostSpec(name="xeon_gold_2s", p_idle_w=90.0, p_active_w=35.0)
 # Default platform used across roofline + energy accounting.
 DEFAULT_CHIP = TPU_V5E
 DEFAULT_HOST = HOST_XEON
+
+# JAX ``device_kind`` -> the chip whose peaks price it. A TPU missing here
+# has no peaks in this package: pricing it as a v5e would be wrong.
+CHIPS = {"TPU v5 lite": TPU_V5E, "TPU v5e": TPU_V5E}
+
+
+def device_chip() -> ChipSpec:
+    """The :class:`ChipSpec` of the TPU that JAX runs on.
+
+    Off the TPU (CPU tests, emulated shards) the models describe the
+    target chip, :data:`DEFAULT_CHIP`. On a TPU whose ``device_kind`` is not
+    in :data:`CHIPS` this raises instead of assuming v5e peaks."""
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return DEFAULT_CHIP
+    chip = CHIPS.get(dev.device_kind)
+    if chip is None:
+        raise ValueError(
+            f"no chip spec for TPU device_kind {dev.device_kind!r}; the "
+            f"energy and roofline models know {sorted(CHIPS)}"
+        )
+    return chip

@@ -14,18 +14,14 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SRC = os.path.join(REPO, "src")
 
-try:  # real hypothesis when available; deterministic stub otherwise
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    import _hypothesis_stub
-
-    _hypothesis_stub.install()
-
 
 def run_multidevice(code: str, n_devices: int = 8, timeout: int = 900, x64: bool = True):
-    """Run a python snippet in a subprocess with N host devices; returns stdout."""
+    """Run a python snippet in a subprocess with N host devices; returns stdout.
+
+    The child is pinned to the CPU: its N devices are emulated host devices,
+    and a parent that already holds a TPU would make a TPU child hang."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     if x64:

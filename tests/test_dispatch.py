@@ -55,6 +55,74 @@ def test_set_backend_validation():
         kd.set_backend(None)
 
 
+@pytest.mark.parametrize("explicit", [True, False])
+def test_f64_pallas_is_refused_or_reported(explicit):
+    # Mosaic has no 64-bit types: an explicit 'pallas' choice raises on f64
+    # operands; only auto resolution may run the jnp reference, and the
+    # energy trace records that choice
+    from repro.energy import trace
+
+    ops_ = kd.OpSet("pallas", auto=not explicit)
+    f64 = np.dtype("float64")
+    if explicit:
+        with pytest.raises(ValueError, match="64-bit"):
+            ops_._mode("fused_dots_n", f64)
+        return
+    with trace.capture() as tr:
+        assert ops_._mode("fused_dots_n", f64) == "jnp"
+        assert ops_._mode("axpy", np.dtype("float32")) == "pallas"
+    assert trace.kernels_by_backend(tr) == {
+        "jnp": ["fused_dots_n"], "pallas": ["axpy"],
+    }
+
+
+def test_interpret_refused_on_tpu(monkeypatch):
+    # nothing runs interpreted on the TPU path
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="interpret"):
+        kd.ops_for("interpret")
+    assert kd.ops_for(None).backend == "pallas"
+    x = jnp.ones(8)
+    with pytest.raises(ValueError, match="interpret"):
+        ops.fused_axpy(2.0, x, x, interpret=True)
+
+
+
+# (variant, dtype, kernels, refused): what a v5e computed wrong is refused
+# on a TPU backend, what it computed right still builds
+TPU_SOLVERS = [
+    ("block", np.float64, None, True),
+    ("block", np.float32, "jnp", True),
+    ("block", np.float32, None, False),
+    ("sstep", np.float32, None, True),
+    ("sstep", np.float64, None, False),
+]
+
+
+@pytest.mark.parametrize("variant,dtype,kernels,refused", TPU_SOLVERS)
+def test_unverified_solvers_refused_on_tpu(monkeypatch, single_mesh, variant,
+                                           dtype, kernels, refused):
+    from repro.core.cg import make_block_solver, make_solver
+    from repro.core.partition import partition_csr
+    from repro.matrices.poisson import cube, poisson_scipy
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with jax.enable_x64(dtype == np.float64):
+        mat = partition_csr(poisson_scipy(cube(4)), 1, dtype=dtype,
+                            halo_depth=2 if variant == "sstep" else 1)
+        if variant == "block":
+            build = lambda: make_block_solver(single_mesh, mat,
+                                              kernels=kernels)
+        else:
+            build = lambda: make_solver(single_mesh, mat, variant="sstep",
+                                        kernels=kernels)
+        if refused:
+            with pytest.raises(ValueError, match="does not .* on a TPU"):
+                build()
+        else:
+            assert callable(build())
+
+
 # ---------------------------------------------------------------------------
 # Fused kernels vs oracles (interpret mode), incl. ragged lengths
 # ---------------------------------------------------------------------------

@@ -82,6 +82,36 @@ def test_bcsr_kernel_sweep(br, bc, n, m, density):
     )
 
 
+@pytest.mark.parametrize("multi_rhs", [False, True])
+def test_bcsr_kernel_split_calls(monkeypatch, multi_rhs):
+    # a matrix with more block ids than one call's SMEM holds runs as
+    # several calls over block-row ranges (a ragged last one included)
+    from repro.kernels import spmv_bcsr
+
+    monkeypatch.setattr(spmv_bcsr, "_IDS_PER_CALL", 10)
+    a = sp.random(52, 44, density=0.15, format="csr", random_state=3)
+    blocks, bcol, n_brows, bpr, n_bcols = pack_bcsr(a, 4, 4, dtype=np.float32)
+    assert n_brows * bpr > 10
+    rng = np.random.default_rng(1)
+    if multi_rhs:
+        x = rng.standard_normal((n_bcols * 4, 3)).astype(np.float32)
+        y = spmv_bcsr.bcsr_spmm(
+            jnp.asarray(blocks), jnp.asarray(bcol),
+            jnp.asarray(x.reshape(n_bcols, 4, 3)), n_brows=n_brows, bpr=bpr,
+            interpret=True,
+        )
+        y = np.asarray(y).reshape(-1, 3)[:52]
+    else:
+        x = rng.standard_normal(n_bcols * 4).astype(np.float32)
+        y = spmv_bcsr.bcsr_spmv(
+            jnp.asarray(blocks), jnp.asarray(bcol),
+            jnp.asarray(x.reshape(n_bcols, 4)), n_brows=n_brows, bpr=bpr,
+            interpret=True,
+        )
+        y = np.asarray(y).reshape(-1)[:52]
+    np.testing.assert_allclose(y, a @ x[:44], rtol=3e-5, atol=3e-5)
+
+
 @pytest.mark.parametrize("n,chunk", [(2048, 512), (8192, 1024), (1024, 1024)])
 @pytest.mark.parametrize("dtype", [np.float32, np.float64])
 def test_fused_dots_sweep(n, chunk, dtype):

@@ -28,7 +28,6 @@ from tests.conftest import run_multidevice
 
 def _unsplit_spmv(mesh, mat, de_full, ce_full, xp):
     """The pre-split formulation: full-row ext block, y = A_loc x + A_ext x."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.spmv import dist_specs, ell_matvec, gather_ext, local_block
@@ -42,7 +41,7 @@ def _unsplit_spmv(mesh, mat, de_full, ce_full, xp):
         y = y + ell_matvec(d[0], c[0], x_ext)
         return y[None]
 
-    f = jax.jit(shard_map(
+    f = jax.jit(jax.shard_map(
         fn, mesh=mesh,
         in_specs=(specs, P("shards", None, None), P("shards", None, None),
                   P("shards", None)),
@@ -70,7 +69,6 @@ def test_split_spmv_bitwise_single_shard(single_mesh):
 
 SPLIT_SNIPPET = r"""
 import numpy as np, jax, jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.matrices.poisson import cube, poisson_scipy
 from repro.core.partition import (partition_csr, partition_stencil,
@@ -101,7 +99,7 @@ for name, build in (("csr", lambda: partition_csr(A, S)),
             x_ext = gather_ext(mb, xv[0], "shards")
             y = ell_matvec(mb.data_loc, mb.col_loc, xv[0])
             return (y + ell_matvec(d[0], c[0], x_ext))[None]
-        f = jax.jit(shard_map(unsplit, mesh=mesh,
+        f = jax.jit(jax.shard_map(unsplit, mesh=mesh,
             in_specs=(specs, P("shards", None, None), P("shards", None, None),
                       P("shards", None)),
             out_specs=P("shards", None)))

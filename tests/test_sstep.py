@@ -102,7 +102,6 @@ def test_depth1_is_bit_identical_to_historical_build(n, bw, n_shards, seed):
 MP_RING_SNIPPET = r"""
 import numpy as np
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 import scipy.sparse as sp
 from repro.core.partition import pad_vector, partition_csr, unpad_vector
@@ -131,9 +130,9 @@ def powers(mesh, mat, p, s, axis="shards"):
     def fn(m, x):
         return matrix_powers(local_block(m), x[0], s, axis)[None]
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(specs, P(axis, None)),
-        out_specs=P(axis, None, None), check_rep=False,
+        out_specs=P(axis, None, None), check_vma=False,
     )(mat, p)
 
 
@@ -148,9 +147,9 @@ def serial(mesh, mat, p, s, axis="shards"):
             outs.append(x[0])
         return jax.numpy.stack(outs)[None]
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(specs, P(axis, None)),
-        out_specs=P(axis, None, None), check_rep=False,
+        out_specs=P(axis, None, None), check_vma=False,
     )(mat, p)
 
 
@@ -189,7 +188,6 @@ def test_matrix_powers_matches_serial_exchanges_ring():
 MP_GRID_SNIPPET = r"""
 import numpy as np
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.core.partition import (
     pad_vector, partition_csr, pencil_partition, unpad_vector,
@@ -218,9 +216,9 @@ def powers(mat, xp, s):
     def fn(m, v):
         return matrix_powers(local_block(m), v[0], s, axis)[None]
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(specs, P(axis, None)),
-        out_specs=P(axis, None, None), check_rep=False,
+        out_specs=P(axis, None, None), check_vma=False,
     )(mat, xp)
 
 
@@ -235,9 +233,9 @@ def serial(mat, xp, s):
             outs.append(v[0])
         return jax.numpy.stack(outs)[None]
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(specs, P(axis, None)),
-        out_specs=P(axis, None, None), check_rep=False,
+        out_specs=P(axis, None, None), check_vma=False,
     )(mat, xp)
 
 

@@ -233,7 +233,6 @@ def test_pod_sum_compressed_matches_psum():
     code = r"""
 import numpy as np
 import jax, jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.dist.compress import compressed_grad_sync, init_error_tree
 
@@ -247,7 +246,7 @@ def f(g_local):
     synced, _ = compressed_grad_sync(grads, err, axis="pod")
     return synced["w"][None]
 
-out = shard_map(f, mesh=mesh, in_specs=P("pod", None), out_specs=P("pod", None))(g)
+out = jax.shard_map(f, mesh=mesh, in_specs=P("pod", None), out_specs=P("pod", None))(g)
 ref = np.mean(np.asarray(g), axis=0)
 got = np.asarray(out)[0]
 rel = np.abs(got - ref).max() / (np.abs(ref).max() + 1e-9)
