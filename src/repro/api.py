@@ -32,6 +32,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import time
 from typing import Any
 
 from repro.obs.log import get_logger
@@ -366,9 +367,16 @@ class SolverSession:
         ``pencil_partition`` layout of a permuted Poisson system);
         ``halo_depth > 1`` builds the s-step ghost zones under a
         depth-tagged key — the same key shape the autotune trial stage
-        uses, so a tuned sstep winner's partition is reused here."""
+        uses, so a tuned sstep winner's partition is reused here.
+
+        A new partition adds its host seconds to the
+        ``setup_partition_csr_seconds`` and ``setup_shard_matrix_seconds``
+        counters of :data:`repro.obs.metrics.REGISTRY`."""
+        import jax
+
         from repro.core.partition import partition_csr
         from repro.core.spmv import shard_matrix
+        from repro.obs.metrics import REGISTRY
 
         if grid is not None:
             grid = (int(grid[0]), int(grid[1]))
@@ -379,11 +387,19 @@ class SolverSession:
         if depth > 1:
             k = k + (("halo", depth),)
         if k not in self.mats:
-            mat = partition_csr(
-                self.a, self.n_shards, fmt=fmt, block=(block, block),
-                grid=grid, partition=partition, halo_depth=depth,
-            )
-            self.mats[k] = shard_matrix(self.mesh_for(mat), mat)
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("repro.partition_csr"):
+                mat = partition_csr(
+                    self.a, self.n_shards, fmt=fmt, block=(block, block),
+                    grid=grid, partition=partition, halo_depth=depth,
+                )
+            t1 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("repro.shard_matrix"):
+                self.mats[k] = shard_matrix(self.mesh_for(mat), mat)
+            t2 = time.perf_counter()
+            REGISTRY.counter("setup_partition_csr_seconds").inc(t1 - t0)
+            # the upload may still be in flight when shard_matrix returns
+            REGISTRY.counter("setup_shard_matrix_seconds").inc(t2 - t1)
             self.partitions += 1
         return self.mats[k]
 
@@ -570,7 +586,6 @@ def solve(
     config.validate()
 
     import contextlib
-    import time
 
     import jax
 

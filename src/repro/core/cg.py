@@ -212,8 +212,8 @@ def _hs_body(A, pre: Preconditioner, pdata, b, x0, *, tol, maxiter, axis, ops,
                     d = all_reduce(jnp.stack([rz_loc, rr_loc[0]]), axis)  # AR 2 (fused)
                     trace.record_collective(2, w.dtype.itemsize)
                 rz_new, rr = d[0], d[1]
-            beta = rz_new / rz
             with trace.region("reductions"):
+                beta = rz_new / rz
                 p = ops.axpy(beta, p, z)
         if telemetry:
             _telemetry_emit(i + 1, jnp.sqrt(rr / jnp.maximum(bb, 1e-300)), axis)
@@ -1035,17 +1035,30 @@ class SolverHandle:
         """Compile under the region trace on first use; no-op afterwards.
 
         Returns the warmup result (blocked until ready), or None when the
-        handle is already warm."""
+        handle is already warm. The compile work of the first call (trace
+        and lowering, backend compile or persistent-cache load, cache hits
+        and misses) is added to the ``warm_*`` counters of
+        :data:`repro.obs.metrics.REGISTRY`."""
         if self.trace is not None:
             return None
-        with trace.capture() as tr:
-            res = self.fn(*args)
-        jax.block_until_ready(res)
+        from repro.obs import metrics
+
+        before = metrics.compile_totals()
+        with jax.profiler.TraceAnnotation("repro.warm"):
+            with trace.capture() as tr:
+                res = self.fn(*args)
+            jax.block_until_ready(res)
+        after = metrics.compile_totals()
+        for key, total in after.items():
+            metrics.REGISTRY.counter(f"warm_{key}").inc(total - before[key])
         self.trace = tr
         return res
 
     def __call__(self, *args):
-        return self.fn(*args)
+        """Dispatch one solve. The ``repro.solve`` profiler span covers the
+        dispatch only: it ends before the device finishes."""
+        with jax.profiler.TraceAnnotation("repro.solve"):
+            return self.fn(*args)
 
 
 #: Process-global handle cache for callers without a session. LRU-bounded:

@@ -1278,22 +1278,25 @@ def expand_boundary(mat: DistMat) -> tuple[np.ndarray, np.ndarray]:
 
 def pad_vector(x: np.ndarray, mat: DistMat) -> np.ndarray:
     """Global vector -> (S, R) padded shard layout."""
-    S, R = mat.n_shards, mat.n_own_pad
-    out = np.zeros((S, R), x.dtype)
-    for s in range(S):
-        lo, hi = mat.row_starts[s], mat.row_starts[s + 1]
-        out[s, : hi - lo] = x[lo:hi]
-    return out
+    with jax.profiler.TraceAnnotation("repro.pad_vector"):
+        S, R = mat.n_shards, mat.n_own_pad
+        out = np.zeros((S, R), x.dtype)
+        for s in range(S):
+            lo, hi = mat.row_starts[s], mat.row_starts[s + 1]
+            out[s, : hi - lo] = x[lo:hi]
+        return out
 
 
 def unpad_vector(xp: np.ndarray, mat: DistMat) -> np.ndarray:
-    """(S, R) padded shard layout -> global vector."""
-    xp = np.asarray(xp)
-    parts = []
-    for s in range(mat.n_shards):
-        lo, hi = mat.row_starts[s], mat.row_starts[s + 1]
-        parts.append(xp[s, : hi - lo])
-    return np.concatenate(parts)
+    """(S, R) padded shard layout -> global vector. A device ``xp`` is
+    copied to the host inside the ``repro.unpad_vector`` span."""
+    with jax.profiler.TraceAnnotation("repro.unpad_vector"):
+        xp = np.asarray(xp)
+        parts = []
+        for s in range(mat.n_shards):
+            lo, hi = mat.row_starts[s], mat.row_starts[s + 1]
+            parts.append(xp[s, : hi - lo])
+        return np.concatenate(parts)
 
 
 def pad_block(X: np.ndarray, mat: DistMat) -> np.ndarray:

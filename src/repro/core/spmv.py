@@ -324,25 +324,34 @@ def spmv_shard(
 
     Both orders compute bitwise-identical results; only the schedule and the
     energy-region attribution differ.
+
+    The device ops carry the step names ``interior``, ``boundary`` and
+    ``halo`` in their ``op_name`` in both orders (plain
+    ``jax.named_scope``s, which leave the energy regions as they are).
     """
     if overlap is None:
         overlap = _OVERLAP_DEFAULT
     ring = mat.plan.mode in ("ring", "grid") and len(mat.plan.shifts) > 0
     if overlap and ring:
         with trace.region(trace.OVERLAP):
-            halo = _halo_exchange(x_own, mat.send_sel, mat.plan, axis)
-            y = interior_matvec(mat.interior, x_own)
-            x_ext = jnp.concatenate([x_own, halo])
-            yb = boundary_matvec(
-                mat.data_ext, mat.col_ext, x_ext, src_elems=halo.shape[0]
-            )
-            return y.at[mat.bnd_rows].add(yb)
+            with jax.named_scope("halo"):
+                halo = _halo_exchange(x_own, mat.send_sel, mat.plan, axis)
+            with jax.named_scope("interior"):
+                y = interior_matvec(mat.interior, x_own)
+            with jax.named_scope("boundary"):
+                x_ext = jnp.concatenate([x_own, halo])
+                yb = boundary_matvec(
+                    mat.data_ext, mat.col_ext, x_ext, src_elems=halo.shape[0]
+                )
+                return y.at[mat.bnd_rows].add(yb)
     x_ext = gather_ext(mat, x_own, axis)
-    y = interior_matvec(mat.interior, x_own)
+    with jax.named_scope("interior"):
+        y = interior_matvec(mat.interior, x_own)
     # ring: the boundary gathers touch only the received halo buffers
     src = x_ext.shape[0] - x_own.shape[0] if ring else None
-    yb = boundary_matvec(mat.data_ext, mat.col_ext, x_ext, src_elems=src)
-    return y.at[mat.bnd_rows].add(yb)
+    with jax.named_scope("boundary"):
+        yb = boundary_matvec(mat.data_ext, mat.col_ext, x_ext, src_elems=src)
+        return y.at[mat.bnd_rows].add(yb)
 
 
 # ---------------------------------------------------------------------------
@@ -486,13 +495,14 @@ def shard_vector(mesh, xp, axis="shards") -> jax.Array:
     """(S, R[, r]) padded host vector or RHS block -> device array sharded
     over the shard axis (all trailing axes replicated). Host input goes
     straight to its shards, never through one device."""
-    if not isinstance(xp, jax.Array):
-        xp = np.asarray(xp)
-        xp = xp.astype(jax.dtypes.canonicalize_dtype(xp.dtype), copy=False)
-    sh = jax.sharding.NamedSharding(
-        mesh, P(axis, *([None] * (xp.ndim - 1)))
-    )
-    return jax.device_put(xp, sh)
+    with jax.profiler.TraceAnnotation("repro.shard_vector"):
+        if not isinstance(xp, jax.Array):
+            xp = np.asarray(xp)
+            xp = xp.astype(jax.dtypes.canonicalize_dtype(xp.dtype), copy=False)
+        sh = jax.sharding.NamedSharding(
+            mesh, P(axis, *([None] * (xp.ndim - 1)))
+        )
+        return jax.device_put(xp, sh)
 
 
 def shard_matrix(mesh, mat: DistMat, axis=None) -> DistMat:

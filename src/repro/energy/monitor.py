@@ -16,8 +16,6 @@ computed exactly per segment.
 from __future__ import annotations
 
 import dataclasses
-import time
-from contextlib import contextmanager
 
 import numpy as np
 
@@ -90,18 +88,15 @@ class PowerMonitor:
         overlap: bool = True,
         hides_comm: bool | None = None,
         repeats: int = 1,
-        duration: float | None = None,
         section: str = "",
     ) -> float:
         """Record a modeled region executing ``counts`` per device.
 
         Returns the modeled duration (seconds) of the whole region.
-        ``duration`` overrides the modeled time (e.g. measured wall time on
-        real hardware); the collective exposed/hidden split always comes
-        from the modeled engine times. ``overlap`` selects the segment's
-        comm schedule: ``max(compute, memory, collective)`` when True,
-        ``max(compute, memory) + collective`` when False. ``hides_comm``
-        controls whether the segment *credits* collective time as hidden
+        ``overlap`` selects the segment's comm schedule: ``max(compute,
+        memory, collective)`` when True, ``max(compute, memory) +
+        collective`` when False. ``hides_comm`` controls whether the
+        segment *credits* collective time as hidden
         (``comm_hidden_s``); default = ``overlap``. Trace-derived ledgers
         pass ``hides_comm`` only for the ``"overlap"`` region, where the
         compute is independent of the collective by construction — a
@@ -111,7 +106,6 @@ class PowerMonitor:
         S = n_shards if n_shards is not None else self.n_devices
         _, (tc, tm, tl) = self.cost.times(counts, S, overlap)
         t, _, _, p = self.cost.device_energy(counts, S, overlap)
-        t = t if duration is None else duration / max(repeats, 1)
         comm_frac = 0.0
         if counts.hbm_bytes + counts.ici_bytes > 0:
             comm_frac = counts.ici_bytes / (counts.hbm_bytes + counts.ici_bytes)
@@ -132,13 +126,6 @@ class PowerMonitor:
                     t_comp, t_mem, t_coll, overlapped, section)
         )
         self._t += dt
-
-    @contextmanager
-    def wall_region(self, name: str, counts: OpCounts, **kw):
-        """Measured-wall-time region (for real-hardware runs)."""
-        t0 = time.perf_counter()
-        yield
-        self.region(name, counts, duration=time.perf_counter() - t0, **kw)
 
     # -- curves & integration ------------------------------------------------
 

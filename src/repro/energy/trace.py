@@ -38,6 +38,8 @@ import contextlib
 import dataclasses
 from collections import Counter
 
+import jax
+
 from repro.energy.accounting import ZERO, OpCounts
 
 DEFAULT_REGION = "other"
@@ -155,10 +157,16 @@ def region(name: str):
     *innermost* active region. Trace-time only: entering a region during
     execution of a compiled program costs nothing (markers run while JAX
     traces the python body).
+
+    The region is also a ``jax.named_scope``: every op traced inside it
+    carries ``name`` as a step of its ``op_name`` (e.g.
+    ``jit(solve)/while/body/spmv/gather``), which is how a device profile
+    names the solver layer each op belongs to.
     """
     _stack.append(name)
     try:
-        yield
+        with jax.named_scope(name):
+            yield
     finally:
         _stack.pop()
 

@@ -41,12 +41,18 @@ def check_devices(n: int):
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache; returns its directory.
 
-    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses it and
-    nothing is set here. Otherwise the cache lives at the fixed
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses it and no
+    directory is set here. Otherwise the cache lives at the fixed
     ``<repo>/.jax_cache``, so every run from this checkout finds what the
-    earlier ones compiled."""
+    earlier ones compiled.
+
+    The cache key includes the programs' metadata: by default JAX strips
+    it, and two programs that differ only in their ``op_name``s (the layer
+    scopes of ``energy/trace.region``) would share an entry, so one would
+    load the other's executable and profile under its names."""
     import jax
 
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -38,7 +38,8 @@ Observability (docs/observability.md): the engine keeps a
 :class:`repro.obs.metrics.MetricsRegistry` — request/batch/eviction
 counters, queue-depth gauge, batch-width / J-per-request / latency
 histograms — snapshotted into the ledger's ``metrics`` block and written
-as Prometheus text via ``--metrics-out``; ``--profile`` exports every
+as Prometheus text via ``--metrics-out``, followed by the process's set-up
+counters (``repro.obs.metrics.REGISTRY``); ``--profile`` exports every
 flushed batch's power timeline as one sequential Chrome trace.
 """
 
@@ -454,9 +455,14 @@ class ServeEngine:
         return self.metrics.snapshot()
 
     def metrics_prometheus(self) -> str:
-        """Prometheus text-exposition snapshot (``--metrics-out``)."""
+        """Prometheus text-exposition snapshot (``--metrics-out``): the
+        engine's instruments, then the process's set-up counters
+        (:data:`repro.obs.metrics.REGISTRY`: partition and compile time,
+        compile-cache hits and misses)."""
+        from repro.obs.metrics import REGISTRY
+
         self._sync_pool_metrics()
-        return self.metrics.to_prometheus()
+        return self.metrics.to_prometheus() + REGISTRY.to_prometheus()
 
     def ledger(self) -> dict:
         """JSON-ready engine ledger; field reference in docs/serving.md."""

@@ -17,11 +17,18 @@ types with no dependencies:
 (``--metrics-out`` on the serving CLI writes it; a scraper can lift the
 file as-is); :meth:`MetricsRegistry.snapshot` returns the same state as a
 JSON-ready dict, embedded in the engine ledger under ``metrics``.
+
+:data:`REGISTRY` is the process's own registry, for what the solver does
+once per process rather than per request: the set-up counters that
+``SolverSession.matrix`` (host seconds of ``partition_csr`` and
+``shard_matrix``) and ``SolverHandle.warm`` (compile work, from JAX's own
+monitoring events through :func:`compile_totals`) add to.
 """
 
 from __future__ import annotations
 
 import math
+import threading
 
 
 class Counter:
@@ -167,3 +174,53 @@ def _fmt(v: float) -> str:
     if math.isfinite(v) and float(v).is_integer():
         return str(int(v))
     return repr(float(v))
+
+
+#: The process-wide registry of the solver's set-up counters.
+REGISTRY = MetricsRegistry()
+
+# JAX's monitoring events of a compile -> the running total they add to
+_COMPILE_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_lower_seconds",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "trace_lower_seconds",
+    # the XLA compile, or the persistent-cache load that replaces it
+    "/jax/core/compile/backend_compile_duration": "backend_compile_seconds",
+}
+_COMPILE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits_total",
+    "/jax/compilation_cache/cache_misses": "cache_misses_total",
+}
+_compile_lock = threading.Lock()
+_compile_totals: dict[str, float] | None = None
+
+
+def _on_duration(event: str, duration: float, **_):
+    key = _COMPILE_DURATIONS.get(event)
+    if key is not None:
+        with _compile_lock:
+            _compile_totals[key] += duration
+
+
+def _on_event(event: str, **_):
+    key = _COMPILE_EVENTS.get(event)
+    if key is not None:
+        with _compile_lock:
+            _compile_totals[key] += 1
+
+
+def compile_totals() -> dict[str, float]:
+    """Running totals of this process's compile work since the first call:
+    seconds tracing and lowering, seconds in the backend compile (or the
+    persistent-cache load), and persistent-cache hits and misses. The
+    first call registers the one listener on JAX's monitoring events; a
+    caller takes the difference of two calls around its own work."""
+    global _compile_totals
+    with _compile_lock:
+        if _compile_totals is None:
+            from jax import monitoring
+
+            _compile_totals = dict.fromkeys(
+                [*_COMPILE_DURATIONS.values(), *_COMPILE_EVENTS.values()], 0.0)
+            monitoring.register_event_duration_secs_listener(_on_duration)
+            monitoring.register_event_listener(_on_event)
+        return dict(_compile_totals)
